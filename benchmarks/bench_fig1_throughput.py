@@ -572,6 +572,7 @@ if __name__ == "__main__":
     ap.add_argument("--json", default=None,
                     help="write a BENCH_fig1.json perf-trajectory record")
     args = ap.parse_args()
+    bc.enable_compile_cache()
     mode = "tiny" if args.tiny else ("full" if args.full else "quick")
     if args.paged:
         results = run_paged(tiny=args.tiny)

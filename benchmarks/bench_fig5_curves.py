@@ -45,4 +45,5 @@ def run(quick: bool = True) -> dict:
 
 
 if __name__ == "__main__":
+    bc.enable_compile_cache()
     run(quick=True)

@@ -33,7 +33,7 @@ def sweep_tuned_vs_default(quick: bool = True) -> dict:
     deterministic default config against the measured winner (both read
     from the same timing table, so the comparison is apples-to-apples)."""
     shapes = {
-        "fused_routing": {"shape": (32, 252, 10, 16),
+        "fused_routing": {"shape": (32, 10, 16, 252),
                           "softmax_mode": "taylor"},
         "taylor_softmax": {"shape": (32 * 252, 10)},
     }
@@ -67,25 +67,25 @@ def sweep_tuned_vs_default(quick: bool = True) -> dict:
 def run(quick: bool = True) -> dict:
     # pruned-MNIST routing shape from the paper: 252 capsules -> 10 x 16
     b, i, j, d = (32, 252, 10, 16) if quick else (128, 252, 10, 16)
-    u = jax.random.normal(jax.random.key(0), (b, i, j, d)) * 0.2
-    blog = jax.random.normal(jax.random.key(1), (b, i, j))
-    c = jax.nn.softmax(blog, -1)
-    s = jnp.einsum("bij,bijd->bjd", c, u)
+    u = jax.random.normal(jax.random.key(0), (b, j, d, i)) * 0.2  # routing layout
+    blog = jax.random.normal(jax.random.key(1), (b, j, i))
+    c = jax.nn.softmax(blog, 1)
+    s = jnp.einsum("bji,bjdi->bjd", c, u)
     v = am.squash(s)
 
     ops = {
-        "softmax(exact)": jax.jit(lambda x: jax.nn.softmax(x, -1)),
+        "softmax(exact)": jax.jit(lambda x: jax.nn.softmax(x, 1)),
         "softmax(taylor Eq.2)": jax.jit(
-            lambda x: am.taylor_softmax(x, -1, range_reduce=True)),
+            lambda x: am.taylor_softmax(x, 1, range_reduce=True)),
         "softmax(taylor+Eq.3 div)": jax.jit(
-            lambda x: am.taylor_softmax(x, -1, range_reduce=True,
+            lambda x: am.taylor_softmax(x, 1, range_reduce=True,
                                         use_div_exp_log=True)),
         "FC (s=c.u)": jax.jit(
-            lambda c_: jnp.einsum("bij,bijd->bjd", c_, u)),
+            lambda c_: jnp.einsum("bji,bjdi->bjd", c_, u)),
         "squash": jax.jit(lambda s_: am.squash(s_)),
         "squash(fast rsqrt)": jax.jit(lambda s_: am.squash_fast(s_)),
         "agreement (b+=u.v)": jax.jit(
-            lambda v_: jnp.einsum("bijd,bjd->bij", u, v_)),
+            lambda v_: jnp.einsum("bjdi,bjd->bji", u, v_)),
     }
     args = {"softmax(exact)": blog, "softmax(taylor Eq.2)": blog,
             "softmax(taylor+Eq.3 div)": blog, "FC (s=c.u)": c,
@@ -132,6 +132,7 @@ if __name__ == "__main__":
     ap.add_argument("--json", default=None,
                     help="write a BENCH_fig8.json perf-trajectory record")
     cli = ap.parse_args()
+    bc.enable_compile_cache()
     results = run(quick=not cli.full)
     if cli.json:
         bc.write_bench_json(cli.json, "fig8", results,

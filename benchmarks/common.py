@@ -13,6 +13,8 @@ import numpy as np
 from repro.core import capsnet as cn
 from repro.core import pruning as pr
 from repro.data import synthetic_digits as sd
+# every benchmark entry point calls bc.enable_compile_cache() first
+from repro.launch.compile_cache import enable_compile_cache  # noqa: F401
 from repro.optim import AdamWConfig
 from repro.training import Trainer, TrainerConfig
 

@@ -39,6 +39,7 @@ def main():
                     help="write one machine-readable BENCH_<key>.json "
                          "perf-trajectory record per bench to this dir")
     args = ap.parse_args()
+    bc.enable_compile_cache()
     only = set(args.only.split(",")) if args.only else set(BENCHES)
 
     failures = []

@@ -108,7 +108,7 @@ def capsnet_defs(cfg: CapsNetConfig) -> Dict[str, Any]:
             "b": ParamDef((c2,), ("conv_out",),
                           lambda k, s, d: jnp.zeros(s, d)),
         },
-        # DigitCaps transform: u_hat[b,i,j,:] = u[b,i,:] @ W[i,j]
+        # DigitCaps transform: u_hat[b,j,:,i] = u[b,i,:] @ W[i,j]
         "digit": {
             "w": ParamDef((n_in, n_out, d_in, d_out),
                           ("caps_in", "caps_out", None, None),
@@ -164,8 +164,9 @@ def primary_capsules(params: Dict[str, Any], cfg: CapsNetConfig,
 
 
 def predictions(params: Dict[str, Any], u: jax.Array) -> jax.Array:
-    """u (B, N_in, d_in) x W (N_in, N_out, d_in, d_out) -> u_hat."""
-    return jnp.einsum("bid,ijde->bije", u, params["digit"]["w"])
+    """u (B, N_in, d_in) x W (N_in, N_out, d_in, d_out) -> u_hat
+    (B, N_out, d_out, N_in): the routing layout, input capsules minor."""
+    return jnp.einsum("bid,ijde->bjei", u, params["digit"]["w"])
 
 
 def digit_capsules(params: Dict[str, Any], cfg: CapsNetConfig,

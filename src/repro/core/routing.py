@@ -1,7 +1,9 @@
 """Dynamic routing between capsules (Sabour et al., paper Fig. 4).
 
-Inputs: prediction vectors ``u_hat`` of shape (B, N_in, N_out, D_out) where
-``u_hat[b, i, j, :]`` is capsule i's prediction for parent capsule j.
+Inputs: prediction vectors ``u_hat`` of shape (B, N_out, D_out, N_in) where
+``u_hat[b, j, :, i]`` is capsule i's prediction for parent capsule j.  The
+input-capsule axis is minor-most so the fused kernel keeps it in the TPU
+lanes (see kernels/routing); every variant takes the same layout.
 
 Algorithm (r iterations, r=3 in the paper):
 
@@ -28,7 +30,7 @@ Variants (``mode``):
         one VMEM-resident Pallas kernel (the paper's "everything in BRAM").
 
 All variants return (v, c_last): parent capsules (B, N_out, D_out) and the
-final coupling coefficients (B, N_in, N_out).
+final coupling coefficients (B, N_out, N_in).
 """
 
 from __future__ import annotations
@@ -44,25 +46,25 @@ from repro.core import approx_math
 
 def _softmax_parents(b: jax.Array, mode: str, use_div_exp_log: bool = False
                      ) -> jax.Array:
-    """Softmax over the parent axis (last axis of (B, N_in, N_out))."""
+    """Softmax over the parent axis (axis 1 of (B, N_out, N_in))."""
     if mode == "taylor":
         return approx_math.taylor_softmax(
-            b, axis=-1, range_reduce=True, use_div_exp_log=use_div_exp_log)
-    return jax.nn.softmax(b, axis=-1)
+            b, axis=1, range_reduce=True, use_div_exp_log=use_div_exp_log)
+    return jax.nn.softmax(b, axis=1)
 
 
 def route_reference(u_hat: jax.Array, n_iters: int = 3,
                     ) -> Tuple[jax.Array, jax.Array]:
     """Oracle implementation — direct transcription of Fig. 4."""
-    bsz, n_in, n_out, d = u_hat.shape
+    bsz, n_out, d, n_in = u_hat.shape
     uf = u_hat.astype(jnp.float32)
-    b = jnp.zeros((bsz, n_in, n_out), jnp.float32)
+    b = jnp.zeros((bsz, n_out, n_in), jnp.float32)
     c = v = None
     for _ in range(n_iters):
-        c = jax.nn.softmax(b, axis=-1)                       # (B, I, J)
-        s = jnp.einsum("bij,bijd->bjd", c, uf)               # FC
+        c = jax.nn.softmax(b, axis=1)                        # (B, J, I)
+        s = jnp.einsum("bji,bjdi->bjd", c, uf)               # FC
         v = approx_math.squash(s, axis=-1)                   # Squash
-        b = b + jnp.einsum("bijd,bjd->bij", uf, v)           # Agreement
+        b = b + jnp.einsum("bjdi,bjd->bji", uf, v)           # Agreement
     return v.astype(u_hat.dtype), c
 
 
@@ -73,26 +75,21 @@ def route_optimized(u_hat: jax.Array, n_iters: int = 3,
     """FastCaps-optimized routing (paper §III-B) in pure JAX.
 
     The contraction layout is the TPU analogue of the paper's reordered
-    loops (Code 2): ``u_hat`` is viewed as (B, N_in, N_out*D) so the FC step
-    ``s = c^T @ u`` and the Agreement step ``b += u @ v`` are single
-    MXU-shaped matmuls over the flattened parent axis, with no scatter into
-    ``b`` (the write conflict the paper removes by making j,k outer loops).
+    loops (Code 2): with the parent axis j leading, the FC step
+    ``s_j = u_j @ c_j`` and the Agreement step ``b_j += v_j @ u_j`` are
+    per-parent matmuls batched over (B, J) and contracted over the
+    input-capsule axis, with no scatter into ``b`` (the write conflict the
+    paper removes by making j,k outer loops).
     """
-    bsz, n_in, n_out, d = u_hat.shape
-    uf = u_hat.astype(jnp.float32).reshape(bsz, n_in, n_out * d)
-    b = jnp.zeros((bsz, n_in, n_out), jnp.float32)
+    uf = u_hat.astype(jnp.float32)
+    bsz, n_out, d, n_in = uf.shape
+    b = jnp.zeros((bsz, n_out, n_in), jnp.float32)
     c = v = None
     for _ in range(n_iters):
         c = _softmax_parents(b, softmax_mode, use_div_exp_log)
-        # FC: (B, J, I) @ (B, I, J*D) -> diag over J — cheaper as one matmul
-        # producing (B, J, J*D) would waste J x; instead contract per-parent
-        # via the (B, I, J, D) view folded to a batched matmul over (I):
-        s = jnp.einsum("bij,bijd->bjd", c, uf.reshape(bsz, n_in, n_out, d))
+        s = jnp.einsum("bji,bjdi->bjd", c, uf)               # FC
         v = approx_math.squash_fast(s, axis=-1)
-        # Agreement as a single (B, I, J*D) x (B, J*D block-diag v) matmul —
-        # flattened: b_ij = sum_d u[b,i,j,d] * v[b,j,d]
-        b = b + jnp.einsum("bijd,bjd->bij",
-                           uf.reshape(bsz, n_in, n_out, d), v)
+        b = b + jnp.einsum("bjdi,bjd->bji", uf, v)           # Agreement
     return v.astype(u_hat.dtype), c
 
 

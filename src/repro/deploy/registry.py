@@ -7,7 +7,8 @@ once with:
   * a ``build(spec)`` factory returning the concrete route function
     ``fn(u_hat, n_iters) -> (v, c)``;
   * an availability probe (e.g. "is the Pallas toolchain importable");
-  * an optional fallback variant used when the probe fails.
+    resolving an unavailable variant raises — no other variant is
+    silently swapped in.
 
 Callers hold a :class:`RoutingSpec` — a small frozen dataclass carried by
 ``CapsNetConfig.routing`` — and resolve it to a callable via
@@ -86,7 +87,6 @@ class RoutingVariant:
     name: str
     build: Callable[[RoutingSpec], RouteFn]
     is_available: Callable[[], bool] = lambda: True
-    fallback: Optional[str] = None    # resolved when is_available() is False
 
 
 class RoutingRegistry:
@@ -109,20 +109,18 @@ class RoutingRegistry:
                 f"{self.names()}") from None
 
     def normalize(self, spec: RoutingSpec) -> RoutingSpec:
-        """Fill backend-dependent fields and apply availability fallback.
+        """Fill backend-dependent fields.
 
         The returned spec is fully concrete: its mode names an available
         variant and (for pallas) ``interpret`` is True/False, chosen from
-        ``jax.default_backend()`` unless the caller pinned it.
+        ``jax.default_backend()`` unless the caller pinned it.  An
+        unavailable variant raises :class:`RuntimeError`.
         """
         variant = self.get(spec.mode)
-        while not variant.is_available():
-            if variant.fallback is None:
-                raise RuntimeError(
-                    f"routing variant {variant.name!r} unavailable and has "
-                    f"no fallback")
-            spec = dataclasses.replace(spec, mode=variant.fallback)
-            variant = self.get(spec.mode)
+        if not variant.is_available():
+            raise RuntimeError(
+                f"routing variant {variant.name!r} is unavailable on this "
+                f"installation")
         if spec.mode == "pallas" and spec.interpret is None:
             from repro.kernels import needs_interpret
 
@@ -178,8 +176,7 @@ def _build_pallas(spec: RoutingSpec) -> RouteFn:
 registry.register(RoutingVariant("reference", _build_reference))
 registry.register(RoutingVariant("optimized", _build_optimized))
 registry.register(RoutingVariant("pallas", _build_pallas,
-                                 is_available=_pallas_available,
-                                 fallback="optimized"))
+                                 is_available=_pallas_available))
 
 
 def resolve(spec: RoutingSpec) -> RouteFn:

@@ -1,13 +1,14 @@
 """Unified kernel registry: typed specs + capability-probed dispatch.
 
-This generalizes the probe-and-fallback design of
+This generalizes the capability-probe design of
 ``repro.deploy.registry`` from routing variants to *every* Pallas kernel
 in the repo.  One :class:`KernelSpec` per kernel declares:
 
   * ``build()`` — the jitted Pallas entry point (lazy import, so merely
     importing ``repro.kernels`` never pulls ``jax.experimental.pallas``);
   * ``reference()`` — the pure-jnp oracle with the same semantics;
-  * ``is_available()`` — the capability probe (Pallas importable);
+  * ``is_available()`` — the capability probe (Pallas importable;
+    dispatch raises when it is false);
   * ``space`` — the FastCaps design space for this kernel: the tunable
     block sizes (measured by :mod:`repro.kernels.tuning`) plus the
     numerics-changing knobs (``softmax_mode``) that benchmarks and the
@@ -22,9 +23,9 @@ per-call overrides > tuned config from the on-disk cache (when the
 :func:`repro.kernels.tuning.tuning` scope or ``tune=`` asks for it) >
 the deterministic legalized defaults (the ``tune=False`` CI path).
 Backend capability (``interpret`` mode off-TPU) is probed in exactly one
-place — :func:`repro.kernels.tuning.needs_interpret` — and an
-unavailable Pallas toolchain falls back to the reference oracle, so the
-same call sites run everywhere.
+place — :func:`repro.kernels.tuning.needs_interpret`.  A kernel whose
+Pallas toolchain is unavailable raises at dispatch: nothing silently
+swaps in the reference oracle, so a run that reports a kernel ran it.
 """
 
 from __future__ import annotations
@@ -143,13 +144,16 @@ class KernelRegistry:
              config: Optional[Dict[str, Any]] = None,
              interpret: Optional[bool] = None,
              tune: Optional[bool] = None, **kwargs) -> Any:
-        """Dispatch ``name`` on ``args``: Pallas impl with the resolved
-        config when available, the jnp reference otherwise.  ``kwargs``
-        are semantic (``n_iters``, ``softmax_mode``, ``causal``, ...);
-        tunable overrides ride in ``config``."""
+        """Dispatch ``name`` on ``args``: the Pallas impl with the resolved
+        config.  ``kwargs`` are semantic (``n_iters``, ``softmax_mode``,
+        ``causal``, ...); tunable overrides ride in ``config``.  Raises
+        :class:`RuntimeError` when the kernel is unavailable — the oracle
+        is for parity checks, never a stand-in."""
         spec = self.get(name)
         if not spec.is_available():
-            return spec.ref_call(*args, **kwargs)
+            raise RuntimeError(
+                f"kernel {name!r} is unavailable on this installation "
+                f"(jax.experimental.pallas cannot be imported)")
         resolved = self.resolve_config(name, *args, overrides=config,
                                        tune=tune, **kwargs)
         if interpret is None:
@@ -166,11 +170,7 @@ def _all_concrete(args) -> bool:
 def _pallas_available() -> bool:
     try:
         from jax.experimental import pallas  # noqa: F401
-    # Capability probe: *any* import failure (missing extra, broken
-    # toolchain, platform plugin) means the same thing — "Pallas
-    # unavailable" — and dispatch falls back to the reference oracle.
-    # capslint: disable=exception-hygiene
-    except Exception:
+    except ImportError:
         return False
     return True
 
@@ -242,7 +242,7 @@ def _build_fused_routing():
     @functools.partial(jax.jit, static_argnames=(
         "n_iters", "softmax_mode", "batch_block", "interpret"))
     def fused_routing_entry(u_hat, n_iters=3, softmax_mode="exact",
-                            batch_block=8, interpret=True):
+                            batch_block=8, *, interpret):
         return fused_routing_pallas(
             u_hat, n_iters=n_iters, softmax_mode=softmax_mode,
             batch_block=batch_block, interpret=interpret)
@@ -261,7 +261,7 @@ def _routing_block_dims(u_hat, **kwargs):
 
 
 def _routing_example(case):
-    shape = case.get("shape", (4, 24, 10, 16))
+    shape = case.get("shape", (4, 10, 16, 24))           # (B, J, D, I)
     u = _rand(case.get("seed", 0), shape, case.get("dtype", "float32"),
               scale=0.2)
     return (u,), {"n_iters": case.get("n_iters", 3),
@@ -280,10 +280,10 @@ registry.register(KernelSpec(
     block_dims=_routing_block_dims,
     make_example=_routing_example,
     example_cases=(
-        {"shape": (4, 24, 10, 16), "softmax_mode": "exact", "atol": 1e-5},
-        {"shape": (9, 30, 10, 16), "softmax_mode": "exact", "atol": 1e-5},
-        {"shape": (6, 36, 5, 8), "softmax_mode": "taylor", "atol": 1e-4},
-        {"shape": (3, 252, 10, 16), "softmax_mode": "taylor", "atol": 1e-4},
+        {"shape": (4, 10, 16, 24), "softmax_mode": "exact", "atol": 1e-5},
+        {"shape": (9, 10, 16, 30), "softmax_mode": "exact", "atol": 1e-5},
+        {"shape": (6, 5, 8, 36), "softmax_mode": "taylor", "atol": 1e-4},
+        {"shape": (3, 10, 16, 252), "softmax_mode": "taylor", "atol": 1e-4},
     ),
     ref_accepts=("n_iters", "softmax_mode"),
     is_available=_pallas_available,
@@ -300,7 +300,7 @@ def _build_taylor_softmax():
     from repro.kernels.softmax.kernel import taylor_softmax_pallas
 
     @functools.partial(jax.jit, static_argnames=("row_block", "interpret"))
-    def taylor_softmax_entry(x, row_block=256, interpret=True):
+    def taylor_softmax_entry(x, row_block=256, *, interpret):
         return taylor_softmax_pallas(x, row_block=row_block,
                                      interpret=interpret)
 
@@ -364,7 +364,7 @@ def _build_flash_attention():
         "interpret"))
     def flash_attention_entry(q, k, v, causal=True, q_offset=0,
                               softmax_mode="exact", q_block=512,
-                              kv_block=512, interpret=True):
+                              kv_block=512, *, interpret):
         """(B, S, H, D) GQA API over the (BK, G, S, D) flash kernel."""
         b, s, h, d = q.shape
         t, nkv = k.shape[1], k.shape[2]
@@ -455,7 +455,7 @@ def _build_flash_attention_dequant():
     def flash_attention_dequant_entry(q, kq, ks, vq, vs, causal=True,
                                       q_offset=0, softmax_mode="exact",
                                       q_block=512, kv_block=512,
-                                      page_size=64, interpret=True):
+                                      page_size=64, *, interpret):
         """(B, S, H, D) GQA API over the int8-KV flash kernel; scales
         (B, T) are shared by the KV heads (per-row quantization)."""
         b, s, h, d = q.shape
@@ -564,7 +564,7 @@ def _build_decode_attention():
         "softmax_mode", "kv_block", "slot_block", "page_size", "interpret"))
     def decode_attention_entry(q, k, v, kv_valid_len, tables=None, ks=None,
                                vs=None, softmax_mode="exact", kv_block=512,
-                               slot_block=1, page_size=64, interpret=True):
+                               slot_block=1, page_size=64, *, interpret):
         """(B, 1, H, D) GQA decode API over the (B, K, G, D) kernels.
 
         Dense cache: k/v (B, T, K, D) (+ optional int8 scales ks/vs
@@ -709,7 +709,7 @@ def _build_fused_sampling():
 
     @functools.partial(jax.jit, static_argnames=("row_block", "interpret"))
     def fused_sampling_entry(logits, temperature, seeds, pos, top_k, top_p,
-                             row_block=8, interpret=True):
+                             row_block=8, *, interpret):
         return fused_sampling_pallas(
             logits, temperature, seeds, pos, top_k, top_p,
             row_block=row_block, interpret=interpret)
@@ -777,7 +777,8 @@ def fused_routing(u_hat, n_iters: int = 3, softmax_mode: str = "exact",
                   batch_block: Optional[int] = None,
                   interpret: Optional[bool] = None,
                   tune: Optional[bool] = None):
-    """Fused dynamic routing: u_hat (B, I, J, D) -> (v, c)."""
+    """Fused dynamic routing: u_hat (B, J, D, I) -> (v (B, J, D),
+    c (B, J, I))."""
     return registry.call(
         "fused_routing", u_hat, n_iters=n_iters, softmax_mode=softmax_mode,
         config={"batch_block": batch_block}, interpret=interpret, tune=tune)

@@ -14,7 +14,9 @@ anywhere — and the draw is a Gumbel-argmax over the kept lanes:
 
 Top-k / top-p restrict the kept lanes via a 30-step bisection over the
 scaled-logit value range (vectorized over rows; no sort, no O(V^2)
-pairwise compare — both are hostile to the TPU vector unit).  The argmax
+pairwise compare — both are hostile to the TPU vector unit).  The steps
+run as a ``fori_loop``: unrolled, 60 full-vocabulary passes make the
+TPU kernel compiler emit code for every one of them.  The argmax
 lane is always kept, and greedy (``temperature <= 0``) bypasses the draw
 entirely with an exact raw-logits argmax, so temperature=0 decode is
 bit-identical to the pre-kernel path.
@@ -53,7 +55,10 @@ def _uniform_lanes(seeds, pos, b: int, v: int):
     h = h ^ (h >> 13)
     h = h * jnp.uint32(_MIX2)
     h = h ^ (h >> 16)
-    u = (h >> 8).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    # (h >> 8) < 2^24 is exact in int32 and in f32; the TPU has no
+    # direct uint32 -> f32 conversion, so the cast goes through int32
+    u = ((h >> 8).astype(jnp.int32).astype(jnp.float32)
+         * jnp.float32(1.0 / (1 << 24)))
     return jnp.maximum(u, jnp.float32(1e-7))
 
 
@@ -66,13 +71,15 @@ def _topk_mask(z, k):
     """
     b, v = z.shape
     k_eff = jnp.clip(jnp.where(k <= 0, v, k), 1, v).astype(jnp.int32)
-    lo = jnp.min(z, axis=-1)
-    hi = jnp.max(z, axis=-1)
-    for _ in range(_BISECT_STEPS):
+
+    def step(_, lo_hi):
+        lo, hi = lo_hi
         mid = 0.5 * (lo + hi)
         ge = jnp.sum((z >= mid[:, None]).astype(jnp.int32), axis=-1) >= k_eff
-        lo = jnp.where(ge, mid, lo)
-        hi = jnp.where(ge, hi, mid)
+        return jnp.where(ge, mid, lo), jnp.where(ge, hi, mid)
+
+    lo, _ = jax.lax.fori_loop(0, _BISECT_STEPS, step,
+                              (jnp.min(z, axis=-1), jnp.max(z, axis=-1)))
     return z >= lo[:, None]
 
 
@@ -86,14 +93,15 @@ def _topp_mask(z, p):
     m = jnp.max(z, axis=-1, keepdims=True)
     e = jnp.exp(z - m)
     probs = e / jnp.sum(e, axis=-1, keepdims=True)
-    lo = jnp.min(z, axis=-1) - 1.0
-    hi = jnp.max(z, axis=-1)
-    for _ in range(_BISECT_STEPS):
+
+    def step(_, lo_hi):
+        lo, hi = lo_hi
         mid = 0.5 * (lo + hi)
-        c = jnp.sum(jnp.where(z > mid[:, None], probs, 0.0), axis=-1)
-        ge = c >= p
-        lo = jnp.where(ge, mid, lo)
-        hi = jnp.where(ge, hi, mid)
+        ge = jnp.sum(jnp.where(z > mid[:, None], probs, 0.0), axis=-1) >= p
+        return jnp.where(ge, mid, lo), jnp.where(ge, hi, mid)
+
+    lo, _ = jax.lax.fori_loop(0, _BISECT_STEPS, step,
+                              (jnp.min(z, axis=-1) - 1.0, jnp.max(z, axis=-1)))
     return (z > lo[:, None]) | (p >= 1.0)[:, None]
 
 

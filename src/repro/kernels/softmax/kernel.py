@@ -40,8 +40,8 @@ def _softmax_kernel(x_ref, o_ref):
     o_ref[...] = o.astype(o_ref.dtype)
 
 
-def taylor_softmax_pallas(x: jax.Array, row_block: int = 256,
-                          interpret: bool = True) -> jax.Array:
+def taylor_softmax_pallas(x: jax.Array, row_block: int = 256, *,
+                          interpret: bool) -> jax.Array:
     """Softmax over the last axis of x (any leading shape) using Eq. 2."""
     shape = x.shape
     n = shape[-1]
@@ -50,6 +50,8 @@ def taylor_softmax_pallas(x: jax.Array, row_block: int = 256,
     rb = min(row_block, rows)
     while rows % rb:
         rb -= 1
+    if rb % 8:                # sublane rule: 8-row blocks or all the rows
+        rb = rows
     out = pl.pallas_call(
         _softmax_kernel,
         grid=(rows // rb,),

@@ -153,7 +153,7 @@ class TuneCache:
     plus the measured timing table for reporting::
 
         {"version": 1,
-         "entries": {"fused_routing|cpu|32x256x16x16|float32":
+         "entries": {"fused_routing|cpu|32x16x16x256|float32":
                      {"config": {"batch_block": 8},
                       "timings": {"batch_block=8": 0.0012, ...}}}}
     """

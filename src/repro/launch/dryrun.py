@@ -1,5 +1,9 @@
 import os
+# 512 virtual host devices, and the CPU platform pinned: a dry run never
+# takes an accelerator, so run_matrix can start one per cell beside a
+# process that holds the chip.
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (architecture x input-shape x
 mesh) cell and record memory / cost / collective analysis.
@@ -29,7 +33,7 @@ import jax.numpy as jnp
 from repro import configs as cfg_lib
 from repro.core import capsnet as capsnet_lib
 from repro.launch import hlo_analysis, hlo_cost
-from repro.launch.mesh import (make_production_mesh, mesh_context,
+from repro.launch.mesh import (make_production_mesh,
                               require_virtual_devices)
 from repro.models import common, lm
 from repro.models.common import LMConfig
@@ -205,7 +209,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool,
     t0 = time.time()
     fn, structs, in_sh, out_sh = build_cell(arch, shape, rules, mesh,
                                             variant)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh)
         lowered = jitted.lower(*structs)
         t_lower = time.time() - t0
@@ -214,8 +218,6 @@ def run_cell(arch: str, shape: str, multi_pod: bool,
 
     ma = compiled.memory_analysis()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):   # older jax: one dict per computation
-        ca = ca[0] if ca else {}
     hlo = compiled.as_text()
     coll = hlo_analysis.collective_stats(hlo)
     census = hlo_analysis.op_census(hlo)

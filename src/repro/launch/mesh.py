@@ -8,28 +8,13 @@ single CPU device unless they explicitly build the mesh.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: AxisType landed after 0.4.x."""
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(AxisType.Auto,) * len(axes))
-
-
-def mesh_context(mesh):
-    """Ambient-mesh context manager across jax versions: ``jax.set_mesh``
-    (new) -> ``jax.sharding.use_mesh`` -> the Mesh object itself (0.4.x)."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    if use_mesh is not None:
-        return use_mesh(mesh)
-    return mesh
+    """``jax.make_mesh`` with every axis ``Auto`` (XLA SPMD propagation);
+    enter it as the ambient mesh with ``jax.set_mesh(mesh)``."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -47,6 +47,7 @@ import jax
 import numpy as np
 
 from repro import configs as cfg_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.serving import (DecodeEngine, DisaggregatedEngine, FIFOScheduler,
                            ImageRequest, InterleavingScheduler,
@@ -387,6 +388,7 @@ def main():
     ap.add_argument("--sparsity", type=float, default=0.6,
                     help="LAKP sparsity for both conv layers (0 = dense)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.multihost and args.scheduler != "disagg":
         ap.error("--multihost requires --scheduler disagg")
     if args.paged and args.arch.startswith("capsnet"):

@@ -28,6 +28,7 @@ from repro.core import capsnet as capsnet_lib
 from repro.core import pruning as pruning_lib
 from repro.data import synthetic_digits, tokens
 from repro.deploy import FastCapsPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.optim import AdamWConfig
 from repro.training import Trainer, TrainerConfig
@@ -133,6 +134,7 @@ def main():
     ap.add_argument("--n-train", type=int, default=512)
     ap.add_argument("--n-test", type=int, default=256)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.arch.startswith("capsnet"):
         train_capsnet(args)
     else:

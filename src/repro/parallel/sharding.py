@@ -265,10 +265,7 @@ def shard_constraint(x, logical_axes, rules: AxisRules):
     """with_sharding_constraint by logical axes; no-op when no mesh is set.
 
     Uses the shape-aware single-pass policy (indivisible dims replicate)."""
-    get_mesh = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_mesh is None:                 # older jax: no ambient-mesh query
-        return x
-    env_mesh = get_mesh()
+    env_mesh = jax.sharding.get_abstract_mesh()
     if env_mesh is None or not env_mesh.axis_names:
         return x
     spec = shape_aware_spec(logical_axes, x.shape, rules,

@@ -152,7 +152,7 @@ class CapsuleEngine(EngineCore):
         for n in self.scheduler.shapes(self.capacity):
             u_hat = (jax.random.normal(
                 jax.random.key(0),
-                (n, cfg.n_primary_caps, cfg.n_classes, cfg.digit_dim))
+                (n, cfg.n_classes, cfg.digit_dim, cfg.n_primary_caps))
                 * 0.2)
             if cache.get(ktuning.cache_key_for(kspec, (u_hat,))) is None:
                 ktuning.autotune(

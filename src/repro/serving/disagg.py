@@ -569,6 +569,9 @@ class DisaggregatedEngine:
         # _dead = submit raised mid-handoff; _draining = retiring, drains
         # but takes no new work; _retired = removed, stats retained
         self._dead: Set[EngineCore] = set()       # guarded-by: _tick_lock
+        # the exception that last marked an engine dead, chained to the
+        # error raised when the whole pool is dead
+        self._death: Optional[BaseException] = None  # guarded-by: _tick_lock
         self._draining: Set[EngineCore] = set()   # guarded-by: _tick_lock
         self._retired: List[EngineCore] = []      # guarded-by: _tick_lock
         self._rr = 0    # round-robin cursor      # guarded-by: _tick_lock
@@ -850,7 +853,7 @@ class DisaggregatedEngine:
                     raise RuntimeError(
                         f"all {len(self.decodes)} decode engines failed; "
                         f"{len(self._handoffs)} handoff(s) requeued and "
-                        f"stranded")
+                        f"stranded") from self._death
                 return moved
 
     def _transfer_one_locked(self, h: CacheHandoff) -> bool:
@@ -891,9 +894,10 @@ class DisaggregatedEngine:
             # handoff is requeued by the caller (never-dropped invariant)
             # and a fully-dead pool raises RuntimeError there.
             # capslint: disable=exception-hygiene
-            except Exception:
+            except Exception as e:
                 self._undedup_pages(h, eng, pinned, full_rows)
                 self._dead.add(eng)
+                self._death = e
                 continue
             self._rr = (self._rr + k + 1) % max(n, 1)
             with self._lock:

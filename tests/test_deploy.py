@@ -22,7 +22,8 @@ def tiny_cfg(**kw):
 
 
 def u_hat(seed, b=2, i=24, j=10, d=16, scale=0.2):
-    return jax.random.normal(jax.random.key(seed), (b, i, j, d)) * scale
+    """Prediction vectors in the routing layout (B, J, D, I)."""
+    return jax.random.normal(jax.random.key(seed), (b, j, d, i)) * scale
 
 
 class TestRegistry:
@@ -53,14 +54,21 @@ class TestRegistry:
         assert spec.interpret is True
 
     def test_unavailable_variant_falls_back(self):
+        """An unavailable variant no longer falls back to another one,
+        even when an available variant is registered: it raises, and
+        the variant takes no fallback argument any more."""
         from repro.deploy.registry import RoutingRegistry, RoutingVariant
 
         reg = RoutingRegistry()
         reg.register(RoutingVariant("opt", lambda s: routing_lib.route_optimized))
         reg.register(RoutingVariant("fancy", lambda s: None,
-                                    is_available=lambda: False,
-                                    fallback="opt"))
-        assert reg.normalize(RoutingSpec(mode="fancy")).mode == "opt"
+                                    is_available=lambda: False))
+        with pytest.raises(RuntimeError, match="'fancy' is unavailable"):
+            reg.normalize(RoutingSpec(mode="fancy"))
+        with pytest.raises(RuntimeError, match="unavailable"):
+            reg.resolve(RoutingSpec(mode="fancy"))
+        with pytest.raises(TypeError):
+            RoutingVariant("x", lambda s: None, fallback="opt")
 
     def test_unavailable_without_fallback_raises(self):
         from repro.deploy.registry import RoutingRegistry, RoutingVariant

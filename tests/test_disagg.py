@@ -357,9 +357,12 @@ class TestFailover:
         dec.submit = boom
         eng = DisaggregatedEngine(pre, [dec])
         eng.submit(Request(prompt=[1, 2, 3], max_new_tokens=4))
-        with pytest.raises(RuntimeError, match="decode engines failed"):
+        with pytest.raises(RuntimeError, match="decode engines failed") as ei:
             eng.run_until_idle()
         assert len(eng._handoffs) == 1              # stranded but not lost
+        # the engine's own error is chained, not only "dead"
+        assert isinstance(ei.value.__cause__, RuntimeError)
+        assert str(ei.value.__cause__) == "killed"
 
 
 def test_disagg_sharded_decode_on_2device_cpu_mesh():

@@ -91,11 +91,10 @@ class TestCollectiveParse:
                     mesh, jax.sharding.PartitionSpec())).sum()
 
         # craft a module with an explicit all-reduce via shard_map psum
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         def g(a):
-            return shard_map(
+            return jax.shard_map(
                 lambda x: jax.lax.psum(x, "x"), mesh=mesh,
                 in_specs=P("x"), out_specs=P())(a)
 

@@ -63,10 +63,10 @@ class TestDefaultBlockSelection:
     def test_routing_odd_batch_gets_largest_divisor(self):
         """The old halving-from-8 collapsed odd batches to batch_block=1;
         the shared tuner default picks the largest divisor instead."""
-        u = jnp.zeros((9, 8, 5, 4))
+        u = jnp.zeros((9, 5, 4, 8))
         cfg = registry.default_config("fused_routing", u)
         assert cfg["batch_block"] == 3
-        u = jnp.zeros((12, 8, 5, 4))
+        u = jnp.zeros((12, 5, 4, 8))
         assert registry.default_config("fused_routing", u)["batch_block"] == 6
 
     def test_flash_blocks_divide_sequence(self):
@@ -92,7 +92,7 @@ class TestDispatchModes:
                                        atol=1e-6)
 
     def test_routing_batch_block_invariance(self):
-        u = jax.random.normal(jax.random.key(0), (8, 24, 10, 16)) * 0.2
+        u = jax.random.normal(jax.random.key(0), (8, 10, 16, 24)) * 0.2
         v1, c1 = kernels.fused_routing(u, batch_block=8)
         v2, c2 = kernels.fused_routing(u, batch_block=2)
         np.testing.assert_allclose(np.asarray(v1), np.asarray(v2),
@@ -108,3 +108,21 @@ class TestDispatchModes:
     def test_unknown_kernel_raises(self):
         with pytest.raises(ValueError, match="unknown kernel"):
             registry.get("nope")
+
+    def test_unavailable_kernel_raises_instead_of_reference(self):
+        """Dispatch never swaps in the jnp oracle: a kernel whose probe
+        says unavailable raises, and its reference is never called."""
+        import dataclasses
+
+        from repro.kernels.registry import KernelRegistry
+
+        def oracle(*a, **k):
+            raise AssertionError("reference must not run")
+
+        reg = KernelRegistry()
+        reg.register(dataclasses.replace(
+            registry.get("taylor_softmax"), reference=lambda: oracle,
+            is_available=lambda: False))
+        with pytest.raises(RuntimeError, match="'taylor_softmax' is "
+                                               "unavailable"):
+            reg.call("taylor_softmax", jnp.zeros((8, 16)), tune=False)

@@ -12,7 +12,8 @@ from repro.kernels.routing import ref as rref
 
 
 def u_hat(seed, b=2, i=24, j=10, d=16, scale=0.2):
-    return jax.random.normal(jax.random.key(seed), (b, i, j, d)) * scale
+    """Prediction vectors in the routing layout (B, J, D, I)."""
+    return jax.random.normal(jax.random.key(seed), (b, j, d, i)) * scale
 
 
 class TestVariantAgreement:
@@ -55,7 +56,7 @@ class TestRoutingProperties:
         """c_ij is a distribution over parents j (softmax output)."""
         uh = u_hat(seed, b=1, i=8, j=5, d=4)
         _, c = routing.route_reference(uh, n_iters=iters)
-        np.testing.assert_allclose(np.asarray(jnp.sum(c, -1)), 1.0,
+        np.testing.assert_allclose(np.asarray(jnp.sum(c, 1)), 1.0,
                                    atol=1e-5)
         assert float(jnp.min(c)) >= 0.0
 
@@ -65,8 +66,8 @@ class TestRoutingProperties:
         uh = u_hat(7, b=4, i=32, j=10, d=16, scale=1.0)
         _, c1 = routing.route_reference(uh, n_iters=1)
         _, c3 = routing.route_reference(uh, n_iters=3)
-        m1 = float(jnp.mean(jnp.max(c1, axis=-1)))
-        m3 = float(jnp.mean(jnp.max(c3, axis=-1)))
+        m1 = float(jnp.mean(jnp.max(c1, axis=1)))
+        m3 = float(jnp.mean(jnp.max(c3, axis=1)))
         assert m3 >= m1
 
     def test_uniform_couplings_at_first_iteration(self):

@@ -105,7 +105,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 from repro import configs as cfg_lib
-from repro.launch.mesh import make_mesh, mesh_context
+from repro.launch.mesh import make_mesh
 from repro.models import lm
 from repro.optim import adamw
 from repro.parallel import sharding as shard_lib
@@ -130,13 +130,11 @@ def step(p, o, b):
         lambda p_: lm.loss_fn(p_, cfg, b), has_aux=True)(p)
     return adamw.apply_updates(p, g, o, ocfg)[:2]
 
-with mesh_context(mesh):
+with jax.set_mesh(mesh):
     compiled = jax.jit(step, in_shardings=(p_sh, o_sh, b_sh),
                        out_shardings=(p_sh, o_sh)).lower(
         params, opt, batch).compile()
 ca = compiled.cost_analysis()
-if isinstance(ca, (list, tuple)):     # older jax: one dict per computation
-    ca = ca[0]
 assert ca["flops"] > 0
 print("SUBPROCESS_DRYRUN_OK", ca["flops"])
 """
