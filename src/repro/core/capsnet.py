@@ -151,16 +151,18 @@ def _conv2d(x: jax.Array, w_oihw: jax.Array, b: jax.Array, stride: int
 def primary_capsules(params: Dict[str, Any], cfg: CapsNetConfig,
                      images: jax.Array) -> jax.Array:
     """images (B, H, W, C) -> squashed primary capsules (B, N_in, caps_dim)."""
-    h = jax.nn.relu(_conv2d(images, params["conv1"]["w"],
-                            params["conv1"]["b"], 1))
-    h = _conv2d(h, params["conv2"]["w"], params["conv2"]["b"],
-                cfg.caps_stride)                      # (B, 6, 6, types*dim)
-    b = h.shape[0]
-    hw = cfg.caps_out_hw
-    # channel layout: (types, dim); capsule index = (type, y, x)
-    h = h.reshape(b, hw, hw, h.shape[-1] // cfg.caps_dim, cfg.caps_dim)
-    h = h.transpose(0, 3, 1, 2, 4).reshape(b, -1, cfg.caps_dim)
-    return approx_math.squash(h, axis=-1)
+    with jax.named_scope("caps.conv1"):
+        h = jax.nn.relu(_conv2d(images, params["conv1"]["w"],
+                                params["conv1"]["b"], 1))
+    with jax.named_scope("caps.primary"):
+        h = _conv2d(h, params["conv2"]["w"], params["conv2"]["b"],
+                    cfg.caps_stride)                  # (B, 6, 6, types*dim)
+        b = h.shape[0]
+        hw = cfg.caps_out_hw
+        # channel layout: (types, dim); capsule index = (type, y, x)
+        h = h.reshape(b, hw, hw, h.shape[-1] // cfg.caps_dim, cfg.caps_dim)
+        h = h.transpose(0, 3, 1, 2, 4).reshape(b, -1, cfg.caps_dim)
+        return approx_math.squash(h, axis=-1)
 
 
 def predictions(params: Dict[str, Any], u: jax.Array) -> jax.Array:
@@ -171,9 +173,11 @@ def predictions(params: Dict[str, Any], u: jax.Array) -> jax.Array:
 
 def digit_capsules(params: Dict[str, Any], cfg: CapsNetConfig,
                    u: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    u_hat = predictions(params, u)
+    with jax.named_scope("caps.u_hat"):
+        u_hat = predictions(params, u)
     route_fn = resolve_routing(cfg.routing_spec())
-    return route_fn(u_hat, n_iters=cfg.routing_iters)
+    with jax.named_scope("caps.route"):
+        return route_fn(u_hat, n_iters=cfg.routing_iters)
 
 
 def decode(params: Dict[str, Any], cfg: CapsNetConfig, v: jax.Array,
@@ -189,10 +193,15 @@ def decode(params: Dict[str, Any], cfg: CapsNetConfig, v: jax.Array,
 
 def forward(params: Dict[str, Any], cfg: CapsNetConfig, images: jax.Array
             ) -> Tuple[jax.Array, jax.Array]:
-    """images -> (class capsule lengths (B, n_classes), capsules v)."""
+    """images -> (class capsule lengths (B, n_classes), capsules v).
+
+    The stages run under named scopes, ``caps.conv1``, ``caps.primary``,
+    ``caps.u_hat``, ``caps.route`` and ``caps.length``, which the
+    compiled program keeps in each op's ``op_name`` metadata."""
     u = primary_capsules(params, cfg, images)
     v, _ = digit_capsules(params, cfg, u)
-    lengths = jnp.linalg.norm(v.astype(jnp.float32), axis=-1)
+    with jax.named_scope("caps.length"):
+        lengths = jnp.linalg.norm(v.astype(jnp.float32), axis=-1)
     return lengths, v
 
 

@@ -206,7 +206,12 @@ class FastCapsPipeline:
             spec = routing
         spec = normalize(spec)
         cfg = dataclasses.replace(self.cfg, routing=spec)
-        fwd = jax.jit(lambda p, x: capsnet_lib.forward(p, cfg, x)[0])
+
+        def capsnet_forward(p, x):
+            return capsnet_lib.forward(p, cfg, x)[0]
+
+        # a named program: jit_capsnet_forward in HLO and device traces
+        fwd = jax.jit(capsnet_forward)
         return DeployedCapsNet(
             cfg=cfg,
             params=self.params,

@@ -99,6 +99,15 @@ def _print_latency(stats) -> None:
               f"p95={p95:.2f} ms")
 
 
+def _print_phases(stats, unit: str) -> None:
+    """Host time of each serving phase per item, and the mean queue wait
+    (``EngineStats.phase_s``, ``queue_wait_s``, ``admitted``)."""
+    for name, us in stats.phase_summary().items():
+        print(f"  phase[{name}]: {us:.1f} us/{unit}")
+    print(f"  queue wait: mean {stats.queue_wait_ms:.2f} ms over "
+          f"{stats.admitted} admitted")
+
+
 def _print_scale_events(events) -> None:
     if not events:
         print("  autoscale: no scale events")
@@ -247,6 +256,7 @@ def serve_lm(args) -> None:
     for c in sorted(completions, key=lambda c: c.rid):
         print(f"  rid={c.rid}: latency={c.latency_s * 1e3:.0f} ms "
               f"{c.tokens}")
+    _print_phases(stats, "token")
 
 
 def serve_capsnet(args) -> None:
@@ -295,6 +305,7 @@ def serve_capsnet(args) -> None:
               f"latency={c.latency_s * 1e3:.1f} ms, "
               f"classes={c.classes[:8].tolist()}"
               f"{'...' if len(c.classes) > 8 else ''}")
+    _print_phases(stats, "frame")
 
 
 def main():

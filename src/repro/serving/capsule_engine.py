@@ -16,6 +16,11 @@ requests through one fixed-shape jitted forward:
   frames submitted while a tick is in flight join the next tick.
 * **FPS / latency stats** — cumulative frames, ticks, padding waste and
   wall-clock, plus per-request latency from submit to completion.
+* **Phase spans** — inside ``serving.step`` each tick is split into
+  ``caps.assemble`` (the per-frame copy into the batch), ``caps.device``
+  (placement, the forward, the blocking readback) and ``caps.scatter``
+  (per-frame results and stream events), each a profiler span and an
+  ``EngineStats.phase_s`` entry.
 
 ``engine = deployed.serve(scheduler=...)`` (on a
 :class:`repro.deploy.DeployedCapsNet`) is the canonical way in.  To
@@ -102,15 +107,20 @@ class CapsuleEngine(EngineCore):
 
     def _step(self, active: List[Tuple[int, SlotTask]], n_batch: int
               ) -> Tuple[List[int], int]:
-        batch = np.zeros((n_batch,) + self._frame_shape, np.float32)
-        for i, (_, task) in enumerate(active):
-            batch[i] = task.payload[1]
-        lengths = np.asarray(jax.block_until_ready(
-            self.deployed.forward(self.scheduler.place(batch))))
-        for i, (_, task) in enumerate(active):
-            k = task.payload[0]
-            self._requests[task.rid].state["lengths"][k] = lengths[i]
-            self._emit(task.rid, (k, int(np.argmax(lengths[i]))))
+        # three spans inside serving.step: host batch assembly, the
+        # device forward with its dispatch and readback, per-frame scatter
+        with self._span("caps.assemble"):
+            batch = np.zeros((n_batch,) + self._frame_shape, np.float32)
+            for i, (_, task) in enumerate(active):
+                batch[i] = task.payload[1]
+        with self._span("caps.device"):
+            lengths = np.asarray(jax.block_until_ready(
+                self.deployed.forward(self.scheduler.place(batch))))
+        with self._span("caps.scatter"):
+            for i, (_, task) in enumerate(active):
+                k = task.payload[0]
+                self._requests[task.rid].state["lengths"][k] = lengths[i]
+                self._emit(task.rid, (k, int(np.argmax(lengths[i]))))
         return [s for s, _ in active], len(active)
 
     def _request_class(self, request: ImageRequest) -> str:

@@ -22,8 +22,16 @@ workload-independent about a slot-based, fixed-shape inference engine:
     ``poll(stream=True)`` drains them while plain ``poll()`` keeps the
     completion-level contract;
   * **cumulative stats** — monotone counters (items, padding waste,
-    ticks, wall-clock, completed requests) plus per-request-class
-    latency histograms (p50/p95), shared by every workload.
+    ticks, wall-clock, completed requests, queue wait) plus
+    per-request-class latency histograms (p50/p95), shared by every
+    workload;
+  * **phase spans** — each phase of ``submit()`` and of the tick is a
+    ``jax.profiler.TraceAnnotation`` (``serving.submit``,
+    ``serving.schedule``, ``serving.admit``, ``serving.step``,
+    ``serving.retire``; workloads nest their own inside ``serving.step``
+    through ``_span``), and its time on the engine clock is summed in
+    ``EngineStats.phase_s`` under the same name, so a profiler trace and
+    the counters name the same host work.
 
 Workload adapters (:class:`repro.serving.CapsuleEngine`,
 :class:`repro.serving.ServeEngine`) subclass this and implement four
@@ -44,6 +52,8 @@ import threading
 import time
 from collections import deque
 from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from repro.kernels import tuning as kernel_tuning
 from repro.serving.schedulers import FIFOScheduler, Scheduler, TickRecord
@@ -198,14 +208,30 @@ class EngineStats:
     ``"host_staged/d2h"``, ``"device_to_device/dispatch"``) plus a
     ``"<transport>/total"`` sum — the yardstick for how much delivery
     cost sits on the decode critical path.
+
+    ``phase_s`` maps a phase span name (``"serving.submit"``,
+    ``"serving.schedule"``, ``"serving.admit"``, ``"serving.step"``,
+    ``"serving.retire"``, and the spans a workload nests inside
+    ``serving.step``, e.g. CapsuleEngine's ``"caps.assemble"``,
+    ``"caps.device"``, ``"caps.scatter"``) to the engine-clock seconds
+    spent in it.  ``wall_s`` is ``serving.admit`` plus ``serving.step``
+    less one-off hook time (``_exclude_tick_time``).  ``queue_wait_s``
+    sums, over every admitted task, its admission time minus its
+    request's submit stamp, and ``admitted`` counts those admissions; a
+    preempted task that requeues is admitted, and counted, again, its
+    wait still measured from the request's submit.
     """
 
     items: int = 0                    # real work units served
     padded: int = 0                   # zero-pad slot waste
     ticks: int = 0                    # engine ticks that did work
-    wall_s: float = 0.0               # time spent in admit+step
+    wall_s: float = 0.0               # admit + step phase time
     completed: int = 0                # requests fully served
     preempted: int = 0                # resident tasks evicted + requeued
+    admitted: int = 0                 # tasks placed in slots (incl. requeued)
+    queue_wait_s: float = 0.0         # sum of admission - submit over them
+    phase_s: Dict[str, float] = dataclasses.field(
+        default_factory=dict)         # phase span name -> seconds in it
     latency: Dict[str, LatencyHistogram] = dataclasses.field(
         default_factory=dict)         # request class -> latency histogram
     depth: Dict[str, DepthHistogram] = dataclasses.field(
@@ -241,6 +267,17 @@ class EngineStats:
         """``{handoff stage: (count, p50 ms, p95 ms)}`` for reporting."""
         return {k: (h.count, h.p50_ms, h.p95_ms)
                 for k, h in sorted(self.transfer.items())}
+
+    def phase_summary(self) -> Dict[str, float]:
+        """``{phase span: us per item}`` for reporting."""
+        return {k: 1e6 * v / self.items if self.items else 0.0
+                for k, v in sorted(self.phase_s.items())}
+
+    @property
+    def queue_wait_ms(self) -> float:
+        """Mean wait of an admitted task in the queue (ms)."""
+        return (1e3 * self.queue_wait_s / self.admitted if self.admitted
+                else 0.0)
 
     # image-serving aliases (Fig. 1 vocabulary)
     fps = throughput
@@ -313,6 +350,49 @@ class _RequestEntry:
     emitted: int = 0                  # next StreamEvent.seq
 
 
+def _profiler_span(name: str):
+    """``TraceAnnotation(name)`` while a profiler trace is being taken,
+    else a shared no-op: an idle annotation still costs its construction,
+    about 1 us, on every use."""
+    return TraceAnnotation(name) if TraceAnnotation.is_enabled() \
+        else _NO_SPAN
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+class _Phase:
+    """One timed phase of the serving loop, reused tick after tick: a
+    profiler span ``name`` (opened only while a trace is being taken)
+    and its elapsed time on ``clock``, kept in ``dt`` and added to
+    ``sink[name]`` at exit."""
+
+    __slots__ = ("name", "dt", "_clock", "_sink", "_span", "_t0")
+
+    def __init__(self, name: str, clock: Callable[[], float],
+                 sink: Dict[str, float]):
+        self.name = name
+        self.dt = 0.0
+        self._clock = clock
+        self._sink = sink
+
+    def __enter__(self) -> "_Phase":
+        self._span = (TraceAnnotation(self.name)
+                      if TraceAnnotation.is_enabled() else None)
+        if self._span is not None:
+            self._span.__enter__()
+        self._t0 = self._clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t = self._clock()
+        if self._span is not None:
+            self._span.__exit__(*exc)
+        self.dt = dt = t - self._t0
+        sink = self._sink
+        sink[self.name] = sink.get(self.name, 0.0) + dt
+
+
 class EngineCore:
     """Slot engine skeleton; subclass and implement the workload hooks.
 
@@ -342,7 +422,8 @@ class EngineCore:
     inherits the ambient :func:`repro.kernels.tuning.tuning` policy.
 
     ``clock`` is injectable so schedulers can be tested against a
-    deterministic time source.
+    deterministic time source; every phase time and queue wait in
+    :class:`EngineStats` is read from it.
     """
 
     def __init__(self, capacity: int, scheduler: Optional[Scheduler] = None,
@@ -362,8 +443,17 @@ class EngineCore:
         self._completions: Deque[Any] = deque()        # guarded-by: _lock
         self._events: Deque[StreamEvent] = deque()     # guarded-by: _lock
         self._stats = EngineStats()                    # guarded-by: _lock
+        # _stats.queue_wait_s runs ahead of its meaning: each task's submit
+        # stamp is taken off as it is queued (once per request, or on a
+        # requeue), its admission time added per tick as n_new * now, and
+        # stats() adds back the stamps of the tasks still queued.  No
+        # per-frame work in the tick.
         self._tick_excluded = 0.0      # one-off hook time (autotuning);
         #                                ticker-private (under _tick_lock)
+        self._tick_phase_s: Dict[str, float] = {}  # this tick's phase
+        #                                times; ticker-private, folded into
+        #                                _stats.phase_s as the tick ends
+        self._phases: Dict[str, _Phase] = {}       # ticker-private
         self._next_rid = 0                             # guarded-by: _lock
         self._lock = threading.Lock()          # queue / requests / stats
         self._tick_lock = threading.Lock()     # one ticker at a time
@@ -430,6 +520,17 @@ class EngineCore:
         serving time, not to a one-time measurement."""
         self._tick_excluded += max(float(seconds), 0.0)
 
+    def _span(self, name: str) -> _Phase:
+        """A phase of the tick (ticker thread only): ``with
+        self._span("caps.device"):`` records a profiler span of that name
+        and adds its engine-clock time to ``EngineStats.phase_s[name]``
+        when the tick ends.  Open one per phase, never per item."""
+        phase = self._phases.get(name)
+        if phase is None:
+            phase = self._phases[name] = _Phase(name, self._clock,
+                                                self._tick_phase_s)
+        return phase
+
     def _request_class(self, request: Any) -> str:
         """Coarse label keying the latency histogram (override per
         workload; a small, bounded set of labels keeps stats O(1))."""
@@ -485,23 +586,30 @@ class EngineCore:
         (from the workload's ``_expand``) on malformed payloads before
         any engine state changes.
         """
-        tasks, state = self._expand(request)
-        prio = int(getattr(request, "priority", 0))
-        with self._lock:
-            rid, self._next_rid = allocate_rid(request, self._requests,
-                                               self._next_rid)
-            for t in tasks:
-                t.rid = rid
-                t.priority = prio
-            entry = _RequestEntry(request=request, tasks=tasks, state=state,
-                                  left=len(tasks), t0=self._clock(),
-                                  cls=self._request_class(request),
-                                  stream=self._wants_stream(request))
-            if not tasks:
-                self._complete_locked(entry, self._clock())
-            else:
-                self._requests[rid] = entry
-                self._queue.extend(tasks)
+        t_in = self._clock()
+        with _profiler_span("serving.submit"):
+            tasks, state = self._expand(request)
+            prio = int(getattr(request, "priority", 0))
+            with self._lock:
+                rid, self._next_rid = allocate_rid(request, self._requests,
+                                                   self._next_rid)
+                for t in tasks:
+                    t.rid = rid
+                    t.priority = prio
+                t0 = self._clock()
+                entry = _RequestEntry(request=request, tasks=tasks,
+                                      state=state, left=len(tasks), t0=t0,
+                                      cls=self._request_class(request),
+                                      stream=self._wants_stream(request))
+                if not tasks:
+                    self._complete_locked(entry, self._clock())
+                else:
+                    self._requests[rid] = entry
+                    self._queue.extend(tasks)
+                    self._stats.queue_wait_s -= len(tasks) * t0
+                phases = self._stats.phase_s
+                phases["serving.submit"] = (phases.get("serving.submit", 0.0)
+                                            + self._clock() - t_in)
         return rid
 
     def poll(self, stream: bool = False) -> List[Any]:
@@ -553,8 +661,25 @@ class EngineCore:
         its request entry (latency clock, stream ``seq``) is untouched.
         Admission then pops the queue at ``scheduler.select()`` instead
         of strictly left (default 0 keeps FIFO).
+
+        Each phase is a span (see :class:`EngineStats` ``phase_s``):
+        ``serving.schedule`` (preemption, phase, plan, admission),
+        ``serving.admit``, ``serving.step`` and ``serving.retire``; their
+        times reach ``stats()`` as the tick ends.
         """
         with self._tick_lock:
+            try:
+                return self._tick_locked()
+            finally:
+                phases = self._tick_phase_s
+                with self._lock:
+                    total = self._stats.phase_s
+                    for k, v in phases.items():
+                        total[k] = total.get(k, 0.0) + v
+                phases.clear()
+
+    def _tick_locked(self) -> bool:
+        with self._span("serving.schedule"):
             with self._lock:
                 queued = list(self._queue)
                 residents = [(s, t) for s, t in enumerate(self._slots)
@@ -573,6 +698,8 @@ class EngineCore:
                 with self._lock:
                     for _, task in reversed(evicted):
                         self._queue.appendleft(task)
+                        self._stats.queue_wait_s -= \
+                            self._requests[task.rid].t0
                     self._stats.preempted += len(evicted)
             with self._lock:
                 n_active = sum(s is not None for s in self._slots)
@@ -603,20 +730,25 @@ class EngineCore:
                             self._slots[s] = task
                             new.append((s, task))
                             n_active += 1
+                    if new:
+                        st = self._stats
+                        st.admitted += len(new)
+                        st.queue_wait_s += len(new) * self._clock()
                 active = [(s, t) for s, t in enumerate(self._slots)
                           if t is not None]
-            if not active:
-                return False
+        if not active:
+            return False
 
-            t0 = self._clock()
-            self._tick_excluded = 0.0
-            finished: List[int] = []
-            items = 0
-            with self._kernel_scope():
+        self._tick_excluded = 0.0
+        finished: List[int] = []
+        items = 0
+        with self._kernel_scope():
+            with self._span("serving.admit") as admit:
                 if new:
                     f, i = self._admit(new)
                     finished += f
                     items += i
+            with self._span("serving.step") as step:
                 done = set(finished)
                 still = [(s, t) for s, t in active if s not in done]
                 n_batch = 0
@@ -627,8 +759,9 @@ class EngineCore:
                     f, i = self._step(still, n_batch)
                     finished += f
                     items += i
-            wall = max(self._clock() - t0 - self._tick_excluded, 0.0)
+        wall = max(admit.dt + step.dt - self._tick_excluded, 0.0)
 
+        with self._span("serving.retire"):
             retired: List[Tuple[int, SlotTask]] = []
             with self._lock:
                 st = self._stats
@@ -650,7 +783,10 @@ class EngineCore:
                 self._release_slot(s, task)   # hooks run lock-released
             self.scheduler.observe(
                 TickRecord(n_active=len(still), n_batch=n_batch, wall_s=wall))
-            return True
+            # the tick's last references to the retired tasks: freeing
+            # them (hundreds of objects a tick) is retirement work too
+            del queued, residents, active, new, still, retired
+        return True
 
     def run_until_idle(self) -> List[Any]:
         """Tick until queue and slots drain; returns the completions
@@ -687,8 +823,9 @@ class EngineCore:
     def stats(self) -> EngineStats:
         """Snapshot of the cumulative :class:`EngineStats` (thread-safe).
 
-        The snapshot is detached — counters and latency histograms are
-        copied, so it never mutates as the engine keeps serving."""
+        The snapshot is detached — counters, phase times and latency
+        histograms are copied, so it never mutates as the engine keeps
+        serving."""
         with self._lock:
             return dataclasses.replace(
                 self._stats,
@@ -698,7 +835,10 @@ class EngineCore:
                        for k, h in self._stats.depth.items()},
                 transfer={k: h.copy()
                           for k, h in self._stats.transfer.items()},
-                pages=dict(self._stats.pages))
+                pages=dict(self._stats.pages),
+                phase_s=dict(self._stats.phase_s),
+                queue_wait_s=self._stats.queue_wait_s + sum(
+                    self._requests[t.rid].t0 for t in self._queue))
 
     @property
     def n_pending(self) -> int:

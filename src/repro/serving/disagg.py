@@ -696,8 +696,9 @@ class DisaggregatedEngine:
             eng.warmup()
 
     def stats(self) -> EngineStats:
-        """Aggregated snapshot: member-engine work counters summed,
-        front-end completion/latency/depth/transfer histograms copied."""
+        """Aggregated snapshot: member-engine work counters (items, ticks,
+        wall, queue wait and admissions, phase times) summed, front-end
+        completion/latency/depth/transfer histograms copied."""
         agg = EngineStats()
         for eng in self._members():
             s = eng.stats()
@@ -705,8 +706,12 @@ class DisaggregatedEngine:
             agg.padded += s.padded
             agg.ticks += s.ticks
             agg.wall_s += s.wall_s
+            agg.admitted += s.admitted
+            agg.queue_wait_s += s.queue_wait_s
             for k, v in s.pages.items():
                 agg.pages[k] = agg.pages.get(k, 0) + v
+            for k, v in s.phase_s.items():
+                agg.phase_s[k] = agg.phase_s.get(k, 0.0) + v
         with self._lock:
             agg.completed = self._stats.completed
             agg.latency = {k: h.copy()

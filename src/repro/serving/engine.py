@@ -141,17 +141,26 @@ class ServeEngine(EngineCore):
                 quantize=quantize_pages)
         else:
             self._pages = None
-        self._decode = jax.jit(
-            lambda p, t, pos, c: lm.decode_step(
-                p, cfg, {"tokens": t, "pos": pos}, c))
-        self._prefill = jax.jit(
-            lambda p, t, ln, idx, c: self._prefill_scatter(p, t, ln, idx, c))
+        # tick programs are named functions, so a device trace shows
+        # jit_lm_decode, jit_lm_prefill, ... rather than jit__lambda_
+        def lm_decode(p, t, pos, c):
+            return lm.decode_step(p, cfg, {"tokens": t, "pos": pos}, c)
+
+        def lm_prefill(p, t, ln, idx, c):
+            return self._prefill_scatter(p, t, ln, idx, c)
+
+        def lm_gather_rows(idx, c):
+            return lm.gather_cache_rows(cfg, idx, c)
+
+        def lm_inject_rows(rows, idx, c):
+            return lm.scatter_cache_rows(cfg, idx, rows, c)
+
+        self._decode = jax.jit(lm_decode)
+        self._prefill = jax.jit(lm_prefill)
         # slot-axis cache row movement (shared by preemption/resume here
         # and by the disaggregated engines, which subclass this one)
-        self._gather = jax.jit(
-            lambda idx, c: lm.gather_cache_rows(cfg, idx, c))
-        self._inject = jax.jit(
-            lambda rows, idx, c: lm.scatter_cache_rows(cfg, idx, rows, c))
+        self._gather = jax.jit(lm_gather_rows)
+        self._inject = jax.jit(lm_inject_rows)
         # paged kernel decode needs per-slot tables threaded into the
         # model; vlm keeps the gather-to-dense fallback (its per-site kv
         # slicing predates pool-shaped leaves)
@@ -159,14 +168,17 @@ class ServeEngine(EngineCore):
                               and self._pages is not None
                               and cfg.family in ("dense", "moe"))
         if self._pages is not None:
-            self._decode_paged = jax.jit(
-                lambda p, t, pos, tb, pool, res: self._decode_paged_impl(
-                    p, t, pos, tb, pool, res))
-            self._prefill_paged = jax.jit(
-                lambda off, p, t, ln, pmap, pref, idx, pool, res:
-                self._prefill_paged_impl(off, p, t, ln, pmap, pref, idx,
-                                         pool, res),
-                static_argnums=(0,))
+            def lm_decode_paged(p, t, pos, tb, pool, res):
+                return self._decode_paged_impl(p, t, pos, tb, pool, res)
+
+            def lm_prefill_paged(off, p, t, ln, pmap, pref, idx, pool,
+                                 res):
+                return self._prefill_paged_impl(off, p, t, ln, pmap, pref,
+                                                idx, pool, res)
+
+            self._decode_paged = jax.jit(lm_decode_paged)
+            self._prefill_paged = jax.jit(lm_prefill_paged,
+                                          static_argnums=(0,))
         super().__init__(capacity=n_slots, scheduler=scheduler, clock=clock,
                          kernel_tune=kernel_tune)
         if self._pages is not None:
