@@ -16,11 +16,19 @@ requests through one fixed-shape jitted forward:
   frames submitted while a tick is in flight join the next tick.
 * **FPS / latency stats** — cumulative frames, ticks, padding waste and
   wall-clock, plus per-request latency from submit to completion.
+* **Per-run assembly and scatter** — frames move between requests and
+  the batch as runs: consecutive batch rows that hold consecutive
+  frames of one request, found from the tick's slots, each copied in and
+  its lengths copied back as one slice.  FIFO admission of whole
+  requests gives a few long runs a tick; fragmented slots (ragged
+  requests, SLO buckets, preemption) give shorter ones, down to one
+  frame.  ``EngineStats.counts["caps.runs"]`` counts the runs moved.
 * **Phase spans** — inside ``serving.step`` each tick is split into
-  ``caps.assemble`` (the per-frame copy into the batch), ``caps.device``
-  (placement, the forward, the blocking readback) and ``caps.scatter``
-  (per-frame results and stream events), each a profiler span and an
-  ``EngineStats.phase_s`` entry.
+  ``caps.assemble`` (finding the runs, one slice copy per run into the
+  batch), ``caps.device`` (placement, the forward, the blocking
+  readback) and ``caps.scatter`` (one slice of lengths per run, and the
+  stream events of streaming requests under one lock), each a profiler
+  span and an ``EngineStats.phase_s`` entry.
 
 ``engine = deployed.serve(scheduler=...)`` (on a
 :class:`repro.deploy.DeployedCapsNet`) is the canonical way in.  To
@@ -41,7 +49,8 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
-from repro.serving.core import EngineCore, EngineStats, SlotTask  # noqa: F401
+from repro.serving.core import (EngineCore, EngineStats,  # noqa: F401
+                                SlotTask, StreamEvent)
 from repro.serving.schedulers import Scheduler, pow2_bucket
 
 
@@ -102,25 +111,61 @@ class CapsuleEngine(EngineCore):
                 f"{imgs.shape}")
         request.images = imgs
         n = imgs.shape[0]
-        state = {"lengths": np.zeros((n, self._n_classes), np.float32)}
-        return [SlotTask(payload=(k, imgs[k])) for k in range(n)], state
+        state = {"images": imgs,
+                 "lengths": np.zeros((n, self._n_classes), np.float32)}
+        return [SlotTask(payload=k) for k in range(n)], state
+
+    def _runs(self, active: List[Tuple[int, SlotTask]]) -> List[List[Any]]:
+        """``active`` (slot order) as runs ``[entry, k0, i0, i1]``: batch
+        rows ``i0:i1`` hold frames ``k0:k0 + i1 - i0`` of ``entry``'s
+        request."""
+        runs: List[List[Any]] = []
+        rid = k = None
+        for i, (_, task) in enumerate(active):
+            if task.rid == rid and task.payload == k + 1:
+                k += 1
+                continue
+            if runs:
+                runs[-1].append(i)
+            rid, k = task.rid, task.payload
+            runs.append([self._requests[rid], k, i])
+        runs[-1].append(len(active))
+        return runs
 
     def _step(self, active: List[Tuple[int, SlotTask]], n_batch: int
               ) -> Tuple[List[int], int]:
         # three spans inside serving.step: host batch assembly, the
-        # device forward with its dispatch and readback, per-frame scatter
+        # device forward with its dispatch and readback, the scatter of
+        # results; assembly and scatter take one slice per run
+        n = len(active)
         with self._span("caps.assemble"):
-            batch = np.zeros((n_batch,) + self._frame_shape, np.float32)
-            for i, (_, task) in enumerate(active):
-                batch[i] = task.payload[1]
+            runs = self._runs(active)
+            batch = np.empty((n_batch,) + self._frame_shape, np.float32)
+            batch[n:] = 0
+            for entry, k0, i0, i1 in runs:
+                batch[i0:i1] = entry.state["images"][k0:k0 + i1 - i0]
         with self._span("caps.device"):
             lengths = np.asarray(jax.block_until_ready(
                 self.deployed.forward(self.scheduler.place(batch))))
         with self._span("caps.scatter"):
-            for i, (_, task) in enumerate(active):
-                k = task.payload[0]
-                self._requests[task.rid].state["lengths"][k] = lengths[i]
-                self._emit(task.rid, (k, int(np.argmax(lengths[i]))))
+            streams = []
+            for run in runs:
+                entry, k0, i0, i1 = run
+                entry.state["lengths"][k0:k0 + i1 - i0] = lengths[i0:i1]
+                if entry.stream:
+                    streams.append(run)
+            classes = (np.argmax(lengths[:n], -1).tolist() if streams
+                       else None)
+            with self._lock:
+                counts = self._stats.counts
+                counts["caps.runs"] = counts.get("caps.runs", 0) + len(runs)
+                for entry, k0, i0, i1 in streams:
+                    rid = entry.request.rid
+                    for j in range(i1 - i0):
+                        self._events.append(StreamEvent(
+                            rid=rid, seq=entry.emitted,
+                            item=(k0 + j, classes[i0 + j])))
+                        entry.emitted += 1
         return [s for s, _ in active], len(active)
 
     def _request_class(self, request: ImageRequest) -> str:

@@ -220,6 +220,12 @@ class EngineStats:
     request's submit stamp, and ``admitted`` counts those admissions; a
     preempted task that requeues is admitted, and counted, again, its
     wait still measured from the request's submit.
+
+    ``counts`` maps a workload counter name to how often the workload's
+    mechanism engaged, e.g. CapsuleEngine's ``"caps.runs"``: the runs of
+    consecutive frames of one request it moved between requests and the
+    batch as single slices (``items / counts["caps.runs"]`` is frames per
+    run).  Workloads add to it under the state lock; empty for the rest.
     """
 
     items: int = 0                    # real work units served
@@ -243,6 +249,8 @@ class EngineStats:
         default_factory=dict)         # paged-KV counters (allocations,
     #                                   prefix hits, prefill savings —
     #                                   see repro.serving.pages)
+    counts: Dict[str, int] = dataclasses.field(
+        default_factory=dict)         # workload counter name -> count
 
     @property
     def throughput(self) -> float:
@@ -836,6 +844,7 @@ class EngineCore:
                 transfer={k: h.copy()
                           for k, h in self._stats.transfer.items()},
                 pages=dict(self._stats.pages),
+                counts=dict(self._stats.counts),
                 phase_s=dict(self._stats.phase_s),
                 queue_wait_s=self._stats.queue_wait_s + sum(
                     self._requests[t.rid].t0 for t in self._queue))

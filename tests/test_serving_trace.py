@@ -2,11 +2,13 @@
 ``phase_s``, ``queue_wait_s`` and ``admitted`` on an injected clock (toy
 engine, CapsuleEngine, preemption, detached snapshots, the disaggregated
 sum), the same span names on the host plane of a CPU profiler trace with
-CapsuleEngine's spans nested in ``serving.step``, and the named CapsNet
-program with its stage scopes."""
+CapsuleEngine's spans nested in ``serving.step``, the named CapsNet
+program with its stage scopes, and CapsuleEngine's per-run assembly and
+scatter with its ``caps.runs`` counter."""
 
 import glob
 import re
+import types
 
 import jax
 import numpy as np
@@ -15,8 +17,9 @@ import pytest
 from engine_testlib import ToyEngine, ToyRequest
 from repro.core import capsnet as cn
 from repro.deploy import FastCapsPipeline
-from repro.serving import (CapsuleEngine, DisaggregatedEngine, ImageRequest,
-                           PriorityScheduler)
+from repro.serving import (CapsuleEngine, DisaggregatedEngine, EngineStats,
+                           FIFOScheduler, ImageRequest,
+                           InterleavingScheduler, PriorityScheduler)
 from repro.traffic import VirtualClock
 
 SERVING = ("serving.submit", "serving.schedule", "serving.admit",
@@ -246,3 +249,163 @@ def test_no_name_is_one_the_benchmark_keys_on(deployed):
     eng.serve([ImageRequest(frames(2))])
     for name in set(eng.stats().phase_s) | set(SCOPES):
         assert "fused_routing" not in name and name != "bench.tick"
+
+
+class RecordingForward:
+    """A numpy stand-in for the deployed forward: keeps a copy of every
+    batch it is given and returns lengths that are a fixed function of
+    each row alone, so a per-frame reference can recompute them."""
+
+    cfg = types.SimpleNamespace(image_hw=28, in_channels=1, n_classes=10)
+
+    def __init__(self):
+        self.batches = []
+
+    def forward(self, x):
+        self.batches.append(np.array(x))
+        return row_lengths(np.asarray(x))
+
+
+def row_lengths(x):
+    flat = x.reshape(len(x), -1)
+    return (flat[:, 1:11] + 0.5 * flat[:, 11:21]).astype(np.float32)
+
+
+def tagged_frames(tag, n):
+    """``n`` random frames whose first pixel reads ``1000 * tag + k``."""
+    x = frames(n, seed=tag)
+    x[:, 0, 0, 0] = 1000 * tag + np.arange(n)
+    return x
+
+
+def fifo_steps(sizes, capacity):
+    """Slot layouts of FIFO ticks over whole requests (name, n frames)."""
+    order = [(name, k) for name, n in sizes for k in range(n)]
+    return [order[i:i + capacity] for i in range(0, len(order), capacity)]
+
+
+class LastFirst(FIFOScheduler):
+    """Admits the newest queued task first: every request lands in
+    descending frame order, one-frame runs side by side."""
+
+    def select(self, queue):
+        return len(queue) - 1
+
+
+# name: (n frames, priority, stream); the script submits them in order,
+# "tick" ticks once; the engine then runs until idle.  `steps` are the
+# slot layouts of the ticks that step, as (request, frame index).
+LAYOUTS = {
+    "whole": dict(
+        capacity=8, scheduler=lambda: None,
+        requests={"a": (4, 0, False), "b": (4, 0, True),
+                  "c": (3, 0, False)},
+        script=["a", "b", "c"],
+        steps=fifo_steps([("a", 4), ("b", 4), ("c", 3)], 8),
+        runs=3),
+    "ragged": dict(
+        capacity=8, scheduler=lambda: None,
+        requests={"a": (1, 0, True), "b": (3, 0, False),
+                  "c": (64, 0, True)},
+        script=["a", "b", "c"],
+        steps=fifo_steps([("a", 1), ("b", 3), ("c", 64)], 8),
+        runs=3 + 8),
+    # a prefill tick seats a0..a2 and idles; the urgent h takes the free
+    # slot 3 and evicts a0 from slot 0, so h is split around a1, a2
+    "preempt": dict(
+        capacity=4,
+        scheduler=lambda: PriorityScheduler(InterleavingScheduler()),
+        requests={"a": (3, 5, True), "h": (2, 0, False)},
+        script=["a", "tick", "h"],
+        steps=[[("h", 0), ("a", 1), ("a", 2), ("h", 1)], [("a", 0)]],
+        runs=4),
+    "reversed": dict(
+        capacity=4, scheduler=LastFirst,
+        requests={"a": (3, 0, True), "b": (2, 0, False)},
+        script=["a", "b"],
+        steps=[[("b", 1), ("b", 0), ("a", 2), ("a", 1)], [("a", 0)]],
+        runs=5),
+}
+
+
+class TestFrameRuns:
+    """CapsuleEngine moves frames as runs of consecutive frames of one
+    request: the batch, the lengths, the stream and the run counter read
+    as a per-frame loop would give them, whatever the slot layout."""
+
+    @pytest.mark.parametrize("layout", sorted(LAYOUTS))
+    def test_runs_match_a_per_frame_reference(self, layout):
+        spec = LAYOUTS[layout]
+        fwd = RecordingForward()
+        eng = CapsuleEngine(fwd, batch_size=spec["capacity"],
+                            scheduler=spec["scheduler"]())
+        images = {name: tagged_frames(tag, spec["requests"][name][0])
+                  for tag, name in enumerate(spec["requests"], 1)}
+        rids = {}
+        for step in spec["script"]:
+            if step == "tick":
+                eng.tick()
+                continue
+            _, prio, stream = spec["requests"][step]
+            req = ImageRequest(images[step].copy(), stream=stream)
+            req.priority = prio
+            rids[step] = eng.submit(req)
+        comps = {c.rid: c for c in eng.run_until_idle()}
+        events = eng.poll(stream=True)
+
+        # batch rows: the frames in slot order, the padding zero
+        assert len(fwd.batches) == len(spec["steps"])
+        for batch, slots in zip(fwd.batches, spec["steps"]):
+            assert batch.shape[0] == spec["capacity"]
+            want = np.stack([images[name][k] for name, k in slots])
+            np.testing.assert_array_equal(batch[:len(slots)], want)
+            assert not batch[len(slots):].any()
+
+        # completions: a per-frame loop over each request's frames
+        for name, rid in rids.items():
+            ref = np.stack([row_lengths(images[name][k:k + 1])[0]
+                            for k in range(len(images[name]))])
+            np.testing.assert_array_equal(comps[rid].lengths, ref)
+            np.testing.assert_array_equal(comps[rid].classes,
+                                          np.argmax(ref, -1))
+
+        # stream: one event per frame of a streaming request, in slot
+        # order tick by tick, each rid's seq counting up, then its done
+        seq = {rid: 0 for rid in rids.values()}
+        want = []
+        for slots in spec["steps"]:
+            for name, k in slots:
+                if spec["requests"][name][2]:
+                    rid = rids[name]
+                    cls = int(np.argmax(row_lengths(images[name][k:k + 1])))
+                    want.append((rid, seq[rid], (k, cls)))
+                    seq[rid] += 1
+        assert [(e.rid, e.seq, e.item) for e in events if not e.done] == want
+        for name, rid in rids.items():
+            mine = [e for e in events if e.rid == rid]
+            if not spec["requests"][name][2]:
+                assert mine == []
+                continue
+            assert mine[-1].done and mine[-1].seq == len(images[name])
+            assert mine[-1].completion is comps[rid]
+            assert all(type(c) is int for e in mine[:-1] for c in e.item)
+
+        assert eng.stats().counts == {"caps.runs": spec["runs"]}
+        assert eng.stats().items == sum(map(len, spec["steps"]))
+
+    def test_counts_snapshot_is_detached(self):
+        eng = CapsuleEngine(RecordingForward(), batch_size=4)
+        eng.serve([ImageRequest(tagged_frames(1, 2)),
+                   ImageRequest(tagged_frames(2, 2))])
+        snap = eng.stats()
+        assert snap.counts == {"caps.runs": 2}
+        snap.counts["caps.runs"] = -1
+        eng.serve([ImageRequest(tagged_frames(3, 3))])
+        assert eng.stats().counts == {"caps.runs": 3}
+        assert snap.counts == {"caps.runs": -1}
+
+    def test_other_engines_count_nothing(self):
+        eng = ToyEngine(capacity=2)
+        eng.serve([ToyRequest(n_tasks=3, steps=2)])
+        assert eng.stats().counts == {}
+        assert EngineStats().counts == {}
